@@ -5,11 +5,6 @@ is an oracle with the SAME structural equations but hidden, per-model true
 parameters plus plan-conditioned efficiency wiggles and measurement noise —
 the scheduler's fitted model never sees the truth, so Table-2-style
 prediction errors are earned, not circular.
-
-``JaxMicroOracle`` additionally grounds t_fwd_unit in REAL measured step
-times of the reduced JAX models on this machine (used by the end-to-end
-pipeline benchmark), so the profiling → fit → predict loop runs against
-actual executions at least at micro scale.
 """
 
 from __future__ import annotations
@@ -214,41 +209,3 @@ def profiling_requests(profiles, oracle: AnalyticOracle,
             skipped.append((profile, samples))
     return requests, skipped
 
-
-class JaxMicroOracle:
-    """Measures REAL wall-clock step times of reduced JAX models on this
-    host, exposing the same .measure() interface at micro scale (dp=1 only;
-    other plan dims fall back to the analytic oracle scaled by the measured
-    single-device time)."""
-
-    def __init__(self, cfg, batch: int = 4, seq: int = 64, steps: int = 3):
-        import time
-
-        import jax
-
-        from repro.configs.base import ShapeConfig
-        from repro.models import ModelOpts, build
-        from repro.train.optimizer import OptConfig, opt_init
-        from repro.train.step import make_train_step
-
-        self.cfg = cfg
-        shape = ShapeConfig("micro", seq, batch, "train")
-        model = build(cfg, ModelOpts(loss_chunk=0))
-        params = model.init(jax.random.PRNGKey(0))
-        opt_state = opt_init(params, OptConfig())
-        step = jax.jit(make_train_step(model, ExecutionPlan(), OptConfig()))
-        batch_data = model.dummy_batch(shape)
-        p, o, _ = step(params, opt_state, batch_data)      # compile
-        jax.block_until_ready(jax.tree.leaves(p)[0])
-        times = []
-        for _ in range(steps):
-            t0 = time.perf_counter()
-            p, o, _ = step(p, o, batch_data)
-            jax.block_until_ready(jax.tree.leaves(p)[0])
-            times.append(time.perf_counter() - t0)
-        self.t_step = float(np.median(times))
-        self.tokens = batch * seq
-
-    def t_fwd_unit(self, k_bwd: float = 2.0) -> float:
-        """Back out per-token fwd time from the measured full step."""
-        return self.t_step / (self.tokens * (1 + k_bwd + 0.2))

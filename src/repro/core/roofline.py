@@ -1,18 +1,15 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
-Per (arch × shape × mesh):
-    compute    = HLO_FLOPs   / (chips × PEAK_BF16)
-    memory     = HLO_bytes   / (chips × HBM_BW)
-    collective = coll_bytes  / (chips × ICI_BW)
+Per (arch × shape × mesh × device kind):
+    compute    = HLO_FLOPs   / (chips × peak bf16 FLOP/s)
+    memory     = HLO_bytes   / (chips × HBM bytes/s)
+    collective = coll_bytes  / (chips × ICI bytes/s)
 
-HLO_FLOPs / bytes come from ``compiled.cost_analysis()``; collective bytes
-are parsed from the optimized HLO text (cost_analysis does not expose them).
-XLA:CPU reports cost_analysis for the whole 512-device program on one host —
-``flops_scope`` is calibrated once with a known matmul (see
-``calibrate_cost_scope``) and cached.
+Per-device FLOPs, bytes and collective bytes come from the loop-aware HLO
+analyzer (``repro.core.hlo_cost``) and are scaled to the whole mesh.
 
-Hardware constants: TPU v5e-class — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (assignment-provided).
+Hardware constants come from ``PEAKS``, keyed by ``device_kind`` as JAX
+reports it; a kind that is not in the table is an error, not a default.
 """
 
 from __future__ import annotations
@@ -22,9 +19,21 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-PEAK_BF16 = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Per-chip peaks: (bf16 FLOP/s, HBM bytes/s, chip-to-chip interconnect
+# bytes/s).  Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+# bf16, 819 GB/s HBM, 1,600 Gbit/s ICI.
+PEAKS = {
+    "TPU v5 lite": (197e12, 819e9, 1600e9 / 8),
+}
+
+
+def peaks(device_kind: str) -> tuple[float, float, float]:
+    """(peak bf16 FLOP/s, HBM bytes/s, ICI bytes/s) of one chip."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -81,35 +90,6 @@ def collective_bytes(hlo_text: str) -> dict[str, float]:
     return out
 
 
-_scope_cache: dict = {}
-
-
-def calibrate_cost_scope(mesh) -> float:
-    """Determine whether cost_analysis() FLOPs are global or per-device on
-    this backend by compiling a known matmul.  Returns divisor so that
-    (reported / divisor) = global FLOPs."""
-    key = tuple(sorted(mesh.shape.items()))
-    if key in _scope_cache:
-        return _scope_cache[key]
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    n = 1024
-    known = 2.0 * n * n * n
-    x = jax.ShapeDtypeStruct((n, n), jnp.float32)
-    daxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    with mesh:
-        f = jax.jit(lambda a, b: a @ b,
-                    in_shardings=(NamedSharding(mesh, P(daxes, None)),
-                                  NamedSharding(mesh, P(None, "model"))))
-        comp = f.lower(x, x).compile()
-    reported = comp.cost_analysis().get("flops", 0.0)
-    scale = reported / known if known else 1.0
-    _scope_cache[key] = scale
-    return scale
-
-
 @dataclass
 class RooflineReport:
     arch: str
@@ -119,6 +99,7 @@ class RooflineReport:
     hlo_flops: float
     hlo_bytes: float
     coll_bytes: float
+    device_kind: str
     coll_breakdown: dict = field(default_factory=dict)
     model_flops: float = 0.0
     attn_flops: float = 0.0
@@ -127,15 +108,15 @@ class RooflineReport:
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / (self.chips * PEAK_BF16)
+        return self.hlo_flops / (self.chips * peaks(self.device_kind)[0])
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / (self.chips * HBM_BW)
+        return self.hlo_bytes / (self.chips * peaks(self.device_kind)[1])
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / (self.chips * ICI_BW)
+        return self.coll_bytes / (self.chips * peaks(self.device_kind)[2])
 
     @property
     def bottleneck(self) -> str:
@@ -155,13 +136,13 @@ class RooflineReport:
     @property
     def roofline_fraction(self) -> float:
         """MODEL_FLOPS-based MFU upper bound at the roofline step time."""
-        ideal = self.model_flops / (self.chips * PEAK_BF16)
+        ideal = self.model_flops / (self.chips * peaks(self.device_kind)[0])
         return ideal / self.t_bound if self.t_bound else 0.0
 
     def row(self) -> dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "hlo_flops": self.hlo_flops, "hlo_bytes": self.hlo_bytes,
             "coll_bytes": self.coll_bytes,
             "t_compute_s": self.t_compute, "t_memory_s": self.t_memory,
@@ -176,8 +157,8 @@ class RooflineReport:
         }
 
 
-def analyze(compiled, *, arch: str, shape, mesh, model_flops: float,
-            attn_flops: float = 0.0, flops_scale: float | None = None,
+def analyze(compiled, *, arch: str, shape, mesh, device_kind: str,
+            model_flops: float, attn_flops: float = 0.0,
             hlo_text: str | None = None) -> RooflineReport:
     """Derive roofline terms from the compiled per-device SPMD module.
 
@@ -205,7 +186,8 @@ def analyze(compiled, *, arch: str, shape, mesh, model_flops: float,
         arch=arch, shape=getattr(shape, "name", str(shape)),
         mesh="x".join(str(v) for v in mesh.shape.values()),
         chips=chips, hlo_flops=flops, hlo_bytes=byts,
-        coll_bytes=sum(coll.values()), coll_breakdown=coll,
+        coll_bytes=sum(coll.values()), device_kind=device_kind,
+        coll_breakdown=coll,
         model_flops=model_flops, attn_flops=attn_flops,
         per_device_peak_bytes=peak,
         dot_by_tag={k: v * chips for k, v in cost.dot_by_tag.items()})

@@ -19,6 +19,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _mm(a, b, contract=((1,), (0,))):
+    """f32 matmul at full precision (the cumulative sums feed exp())."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scr, *, Q: int):
     ci = pl.program_id(1)
 
@@ -32,29 +39,31 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scr, *, Q: int):
     w = w_ref[0].astype(jnp.float32)          # (Q, dk) log-decay ≤ 0
     u = u_ref[0].astype(jnp.float32)          # (1, dk) bonus
 
-    cw = jnp.cumsum(w, axis=0)                # inclusive
+    # Inclusive cumulative sum over the chunk as a lower-triangular-ones
+    # matmul (Mosaic has no cumsum); the column sums give the chunk total.
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    cw = _mm((row >= col).astype(jnp.float32), w)            # (Q, dk)
+    cw_last = _mm(w, jnp.ones((Q, 1), jnp.float32),
+                  ((0,), (0,)))                               # (dk, 1)
     # intra: scores[t,i] = Σ_c r[t,c]·e^{cw[t]-w[t]-cw[i]}·k[i,c], i < t.
     # The exponent cw[t]-w[t]-cw[i] ≤ 0 for i ≤ t-1, so exp() never
     # overflows (the factored e^{-cw[i]} alone would).
     rd = r * jnp.exp(cw - w)                  # (Q, dk)
-    expo = (cw - w)[:, None, :] - cw[None, :, :]          # (Q, Q, dk)
-    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) > \
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    dec = jnp.where(mask[:, :, None], jnp.exp(expo), 0.0)
-    scores = jnp.einsum("tc,tic,ic->ti", r, dec, k)        # (Q, Q)
-    y = jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    expo = (cw - w)[:, None, :] - cw[None, :, :]              # (Q, Q, dk)
+    past = jax.lax.broadcasted_iota(jnp.int32, expo.shape, 0) > \
+        jax.lax.broadcasted_iota(jnp.int32, expo.shape, 1)    # i < t
+    dec = jnp.where(past, jnp.exp(expo), 0.0)
+    scores = jnp.sum(r[:, None, :] * dec * k[None, :, :], axis=-1)  # (Q, Q)
+    y = _mm(scores, v)
     # diagonal bonus
-    diag = jnp.sum(r * u * k, axis=1)                      # (Q,)
-    y = y + diag[:, None] * v
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)          # (Q, 1)
+    y = y + diag * v
     # inter-chunk: y += (r ⊙ e^{cw-w}) S_prev
-    y = y + jax.lax.dot_general(rd, s_scr[...], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    y = y + _mm(rd, s_scr[...])
     # state: S = diag(e^{cw_last}) S + Σ_i e^{cw_last - cw_i} k_i ⊗ v_i
-    kdec = k * jnp.exp(cw[-1:, :] - cw)                    # (Q, dk)
-    s_scr[...] = s_scr[...] * jnp.exp(cw[-1])[:, None] + \
-        jax.lax.dot_general(kdec, v, (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    kdec = k * jnp.exp(cw[Q - 1:Q, :] - cw)                   # (Q, dk)
+    s_scr[...] = s_scr[...] * jnp.exp(cw_last) + _mm(kdec, v, ((0,), (0,)))
     y_ref[0] = y.astype(y_ref.dtype)
 
 

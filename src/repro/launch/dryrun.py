@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × input-shape)
-cell on the production mesh and derive roofline terms.
+cell on the production mesh and derive roofline terms for a TPU v5e.
 
-The two lines above MUST run before any other import (jax locks the device
-count on first init); 512 placeholder host devices back the 16×16 single-pod
-and 2×16×16 multi-pod meshes.
+``main()`` asks XLA for 512 placeholder host devices (the 16×16 single-pod
+and 2×16×16 multi-pod meshes) before JAX first initializes its backends;
+importing this module changes nothing.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k
@@ -19,6 +16,7 @@ Outputs one JSON row per cell under benchmarks/results/.
 import argparse
 import json
 import math
+import os
 import time
 import traceback
 from pathlib import Path
@@ -28,6 +26,7 @@ import jax
 from repro import configs
 from repro.configs.base import SHAPES, ModelConfig, ShapeConfig, shape_applicable
 from repro.core import costs, roofline
+from repro.launch.cache import init_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import ModelOpts, build
 from repro.parallel.plan import ExecutionPlan
@@ -39,6 +38,9 @@ RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results"
 
 # Activation-carry budget per device used to derive the GA factor (bytes).
 ACT_BUDGET = 4e9
+
+# The chip whose peaks price the roofline terms (``roofline.PEAKS``).
+DEVICE_KIND = "TPU v5 lite"
 
 
 def default_plan(cfg: ModelConfig, shape: ShapeConfig, mesh,
@@ -120,7 +122,7 @@ def run_cell(arch: str, shape_name: str, mesh, *, schedule: str = "dense",
     t_compile = time.time() - t0
 
     rep = roofline.analyze(
-        compiled, arch=arch, shape=shape, mesh=mesh,
+        compiled, arch=arch, shape=shape, mesh=mesh, device_kind=DEVICE_KIND,
         model_flops=costs.model_flops(cfg, shape),
         attn_flops=costs.attention_flops(cfg, shape))
     ma = compiled.memory_analysis()
@@ -149,6 +151,8 @@ def run_cell(arch: str, shape_name: str, mesh, *, schedule: str = "dense",
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

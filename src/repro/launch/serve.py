@@ -23,9 +23,11 @@ def main() -> None:
 
     from repro import configs
     from repro.configs.base import ShapeConfig
+    from repro.launch.cache import init_compile_cache
     from repro.models import build
     from repro.serve.engine import ServeEngine
 
+    print(f"[serve] compile cache: {init_compile_cache()}")
     cfg = configs.get(args.arch) if args.full else \
         configs.get_reduced(args.arch)
     model = build(cfg)

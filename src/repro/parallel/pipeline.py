@@ -72,13 +72,6 @@ def pipeline_forward(layer_fn: Callable, stacked_params, x_micro,
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), stacked_params)
-    if hasattr(jax, "shard_map"):            # jax ≥ 0.6
-        fn = jax.shard_map(stage_body, mesh=mesh,
-                           in_specs=(pspec, P()), out_specs=P(),
-                           check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(stage_body, mesh=mesh,
-                       in_specs=(pspec, P()), out_specs=P(),
-                       check_rep=False)
+    fn = jax.shard_map(stage_body, mesh=mesh, in_specs=(pspec, P()),
+                       out_specs=P(), check_vma=False)
     return fn(stacked_params, x_micro)

@@ -34,7 +34,8 @@ def compile_decode_step(model: Model, plan: ExecutionPlan, mesh,
         if shape.global_batch % sh.axis_size(mesh, daxes) == 0 else P(None)
     tok_shard = NamedSharding(mesh, tok_spec)
 
-    with mesh, logical_axis_rules(sh.activation_rules(mesh, plan), dict(mesh.shape)):
+    with jax.set_mesh(mesh), logical_axis_rules(sh.activation_rules(mesh, plan),
+                                                dict(mesh.shape)):
         jitted = jax.jit(
             model.decode_step,
             in_shardings=(p_shard, c_shard, tok_shard),
@@ -57,7 +58,8 @@ def compile_prefill(model: Model, plan: ExecutionPlan, mesh,
     batch = model.input_specs(shape)
     b_shard = sh.named(sh.batch_specs(batch, mesh, plan), mesh)
 
-    with mesh, logical_axis_rules(sh.activation_rules(mesh, plan), dict(mesh.shape)):
+    with jax.set_mesh(mesh), logical_axis_rules(sh.activation_rules(mesh, plan),
+                                                dict(mesh.shape)):
         jitted = jax.jit(
             model.prefill,
             in_shardings=(p_shard, c_shard, b_shard),

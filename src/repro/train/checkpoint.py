@@ -78,6 +78,7 @@ class CheckpointManager:
         self.async_save = async_save
         self.recorder = recorder       # flight recorder (repro.obs), opt-in
         self._thread: threading.Thread | None = None
+        self._error: BaseException | None = None
 
     # ------------------------------------------------------------------
     def save(self, step: int, params: Any, opt_state: Any | None = None,
@@ -120,17 +121,27 @@ class CheckpointManager:
                 self.recorder.span("checkpoint-save", t0, perf_counter(),
                                    float(step), bytes=nbytes)
 
+        def _write_async():
+            try:
+                _write()
+            except BaseException as e:
+                self._error = e
+
         if self.async_save and not block:
-            self._thread = threading.Thread(target=_write, daemon=True)
+            self._thread = threading.Thread(target=_write_async, daemon=True)
             self._thread.start()
         else:
             _write()
         return target
 
     def wait(self) -> None:
+        """Join the async writer; re-raise whatever it raised."""
         if self._thread is not None:
             self._thread.join()
             self._thread = None
+        if self._error is not None:
+            err, self._error = self._error, None
+            raise err
 
     @staticmethod
     def restore_cost_estimate(params: Any,

@@ -96,7 +96,8 @@ def compile_train_step(model: Model, plan: ExecutionPlan, mesh,
     step = make_train_step(model, plan, optcfg)
     metric_shard = NamedSharding(mesh, P())
 
-    with mesh, logical_axis_rules(sh.activation_rules(mesh, plan), dict(mesh.shape)):
+    with jax.set_mesh(mesh), logical_axis_rules(sh.activation_rules(mesh, plan),
+                                                dict(mesh.shape)):
         jitted = jax.jit(
             step,
             in_shardings=(p_shard, o_shard, b_shard),

@@ -46,6 +46,16 @@ def test_checkpoint_restore_missing_raises(tmp_path):
         mgr.restore({"w": jnp.zeros((2,))})
 
 
+def test_checkpoint_async_error_reraised_on_wait(tmp_path):
+    import shutil
+    mgr = CheckpointManager(tmp_path / "ck")
+    shutil.rmtree(tmp_path / "ck")          # the writer thread cannot write
+    mgr.save(1, {"w": jnp.zeros((2,))})
+    with pytest.raises(FileNotFoundError):
+        mgr.wait()
+    mgr.wait()                              # raised once, then cleared
+
+
 # --- data pipeline --------------------------------------------------------------
 
 def test_data_deterministic():
@@ -183,8 +193,17 @@ def test_roofline_bottleneck_math():
     from repro.core.roofline import RooflineReport
     r = RooflineReport(arch="x", shape="train_4k", mesh="16x16", chips=256,
                        hlo_flops=1e18, hlo_bytes=1e15, coll_bytes=1e12,
-                       model_flops=5e17)
+                       device_kind="TPU v5 lite", model_flops=5e17)
     assert r.t_compute == pytest.approx(1e18 / (256 * 197e12))
     assert r.bottleneck in ("compute", "memory", "collective")
     assert 0 < r.useful_ratio <= 1
     assert 0 < r.roofline_fraction <= 1
+
+
+def test_roofline_unknown_device_kind_raises():
+    from repro.core.roofline import RooflineReport, peaks
+    assert peaks("TPU v5 lite") == (197e12, 819e9, 200e9)
+    r = RooflineReport(arch="x", shape="s", mesh="1", chips=1, hlo_flops=1.0,
+                       hlo_bytes=1.0, coll_bytes=0.0, device_kind="cpu")
+    with pytest.raises(KeyError, match="cpu"):
+        r.t_compute

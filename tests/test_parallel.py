@@ -29,7 +29,8 @@ for _ in range(3):
 ref_loss = float(m["loss"])
 
 # sharded
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(dp=2, tp=2)
 plan = ExecutionPlan(dp=2, tp=2, zero_stage=1)
 lowered, p_sh, o_sh, b_sh = compile_train_step(
     model, plan, mesh, optcfg, model.input_specs(shape), donate=False)
@@ -68,7 +69,8 @@ ref_step = jax.jit(make_train_step(model, ExecutionPlan(ga_steps=2), optcfg))
 p, o, m = ref_step(params, opt_init(params, optcfg), batch)
 ref = float(m["loss"])
 
-mesh = jax.make_mesh((4, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(dp=4, tp=1)
 plan = ExecutionPlan(dp=4, tp=1, zero_stage=3, ga_steps=2, gc=True)
 lowered, p_sh, o_sh, b_sh = compile_train_step(
     model, plan, mesh, optcfg, model.input_specs(shape), donate=False)
@@ -104,7 +106,8 @@ cache = model.init_cache(4, 16)
 tok = jnp.array([1,2,3,4], jnp.int32)
 c1, ref_logits = jax.jit(model.decode_step)(params, cache, tok)
 
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(dp=1, tp=4)
 shape = ShapeConfig("d", 16, 4, "decode")
 lowered, p_sh, c_sh = compile_decode_step(model, ExecutionPlan(dp=1, tp=4),
                                           mesh, shape, donate=False)

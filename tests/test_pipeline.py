@@ -24,7 +24,8 @@ def seq(x1):
     return h
 want = jax.vmap(seq)(x)
 
-mesh = jax.make_mesh((4,), ("pipe",))
+from repro.launch.mesh import mesh_of
+mesh = mesh_of((4,), ("pipe",))
 got = pipeline_forward(layer, params, x, mesh)
 err = float(jnp.max(jnp.abs(got - want)))
 print("err", err)
