@@ -33,7 +33,6 @@ BENCHES = [
     "bench_sim_scale",
     "bench_sched_scale",
     "bench_calibration",
-    "bench_roofline",
     "bench_failures",
     "bench_grayfail",
 ]

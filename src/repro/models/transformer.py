@@ -33,6 +33,18 @@ class ModelOpts:
     mtp_loss_weight: float = 0.3
 
 
+# The training path's ``jax.named_scope`` names: ``embed`` (token embedding),
+# ``layers`` (the stacked-layer scan), inside each block ``norm``, ``attn``,
+# ``mlp`` or ``moe``, the final ``norm``, ``loss`` (head matmul and
+# cross-entropy) and ``optimizer`` (``train.optimizer.opt_update``).  They
+# change the compiled HLO's ``op_name`` metadata (and a few names the
+# compiler derives from it), never its instructions.  The benchmark reads
+# them (``perfbench/lib/scopes.py``) to split the device's time in a
+# profiler trace by layer.
+SCOPES = ("embed", "layers", "norm", "attn", "mlp", "moe", "loss", "optimizer")
+EMBED, LAYERS, NORM, ATTN, MLP, MOE, LOSS, OPTIMIZER = SCOPES
+
+
 def _maybe_remat(fn, opts: ModelOpts):
     if opts.remat == "full":
         return jax.checkpoint(fn)
@@ -135,18 +147,23 @@ def block_init(key, cfg: ModelConfig, n_stack: int, kind: str, dtype) -> dict:
 
 def block_apply(lp, x, cfg: ModelConfig, positions, opts: ModelOpts):
     """Pre-norm residual block.  Returns (x, aux_loss)."""
-    h = nn.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-    if cfg.mla:
-        a = mla_mod.mla_attention(lp["attn"], h, cfg, positions,
-                                  schedule=opts.attn_schedule)
-    else:
-        a = attn_apply(lp["attn"], h, cfg, positions, opts)
+    with jax.named_scope(NORM):
+        h = nn.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+    with jax.named_scope(ATTN):
+        if cfg.mla:
+            a = mla_mod.mla_attention(lp["attn"], h, cfg, positions,
+                                      schedule=opts.attn_schedule)
+        else:
+            a = attn_apply(lp["attn"], h, cfg, positions, opts)
     x = shard(x + a, "batch", "seq", "embed")
-    h = nn.rmsnorm(x, lp["ln2"], cfg.norm_eps)
+    with jax.named_scope(NORM):
+        h = nn.rmsnorm(x, lp["ln2"], cfg.norm_eps)
     if "moe" in lp:
-        f, aux = moe_mod.moe_apply(lp["moe"], h, cfg, opts.moe_token_chunk)
+        with jax.named_scope(MOE):
+            f, aux = moe_mod.moe_apply(lp["moe"], h, cfg, opts.moe_token_chunk)
     else:
-        f, aux = nn.ffn_apply(lp["mlp"], h, cfg.act), 0.0
+        with jax.named_scope(MLP):
+            f, aux = nn.ffn_apply(lp["mlp"], h, cfg.act), 0.0
     x = shard(x + f, "batch", "seq", "embed")
     return x, aux
 
@@ -211,20 +228,23 @@ def embed_inputs(params, batch: dict, cfg: ModelConfig):
 
 def decoder_forward(params, batch: dict, cfg: ModelConfig, opts: ModelOpts):
     """Returns (hidden (B,S_total,D), aux_loss, text_offset)."""
-    x, off = embed_inputs(params, batch, cfg)
-    S = x.shape[1]
-    positions = jnp.arange(S)[None, :]
+    with jax.named_scope(EMBED):
+        x, off = embed_inputs(params, batch, cfg)
     aux = 0.0
-    if cfg.n_experts:
-        if cfg.n_dense_layers:
-            x, a = _scan_stack(params["dense_layers"], x, cfg, positions, opts)
+    with jax.named_scope(LAYERS):
+        positions = jnp.arange(x.shape[1])[None, :]
+        if cfg.n_experts:
+            if cfg.n_dense_layers:
+                x, a = _scan_stack(params["dense_layers"], x, cfg, positions,
+                                   opts)
+                aux += a
+            x, a = _scan_stack(params["moe_layers"], x, cfg, positions, opts)
             aux += a
-        x, a = _scan_stack(params["moe_layers"], x, cfg, positions, opts)
-        aux += a
-    else:
-        x, a = _scan_stack(params["layers"], x, cfg, positions, opts)
-        aux += a
-    x = nn.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        else:
+            x, a = _scan_stack(params["layers"], x, cfg, positions, opts)
+            aux += a
+    with jax.named_scope(NORM):
+        x = nn.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     return x, aux, off
 
 
@@ -239,10 +259,11 @@ def decoder_loss(params, batch: dict, cfg: ModelConfig, opts: ModelOpts):
     h, aux, off = decoder_forward(params, batch, cfg, opts)
     if off:
         h = h[:, off:]
-    labels = jnp.roll(tokens, -1, axis=1)
-    mask = jnp.ones_like(tokens, jnp.float32).at[:, -1].set(0.0)
-    loss = nn.cross_entropy_loss(logits_fn(params, cfg), h, labels, mask,
-                                 chunk=opts.loss_chunk)
+    with jax.named_scope(LOSS):
+        labels = jnp.roll(tokens, -1, axis=1)
+        mask = jnp.ones_like(tokens, jnp.float32).at[:, -1].set(0.0)
+        loss = nn.cross_entropy_loss(logits_fn(params, cfg), h, labels, mask,
+                                     chunk=opts.loss_chunk)
     metrics = {"ce": loss}
     if cfg.n_experts:
         loss = loss + opts.aux_loss_weight * aux

@@ -14,6 +14,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.models.transformer import OPTIMIZER
+
 
 @dataclass(frozen=True)
 class OptConfig:
@@ -53,7 +55,13 @@ def global_norm(tree) -> jax.Array:
 
 
 def opt_update(grads, state, params, cfg: OptConfig):
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics), all under the ``optimizer``
+    named scope (``models.transformer.SCOPES``)."""
+    with jax.named_scope(OPTIMIZER):
+        return _update(grads, state, params, cfg)
+
+
+def _update(grads, state, params, cfg: OptConfig):
     count = state["count"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9)) \
