@@ -14,6 +14,11 @@ Two schedules:
                    the masked-out tiles from the compiled FLOPs entirely.
 
 GQA/MQA are expressed by grouping query heads over kv heads.
+
+On a TPU, full-sequence causal self-attention under the default schedule
+runs through the splash attention kernels instead (``repro.kernels.splash``:
+forward, dQ and dKV in VMEM, masked-out blocks skipped); see
+``_kernel_applies`` for when.
 """
 
 from __future__ import annotations
@@ -22,8 +27,55 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax._src import mesh as mesh_lib
+
+from repro.kernels import splash
+from repro.parallel.axes import logical_to_spec
 
 NEG_INF = -1e30
+
+
+def _platform() -> str:
+    """Platform of the devices this trace compiles for: those of the mesh
+    that ``jax.set_mesh`` installed (``train.step`` does), else the default
+    backend's.  ``jax.sharding.get_mesh`` refuses to answer inside a trace,
+    so the installed mesh is read directly."""
+    mesh = mesh_lib.get_concrete_mesh()
+    return jax.default_backend() if mesh.empty else mesh.devices.flat[0].platform
+
+
+def _kernel_applies(q, k, v, causal: bool, schedule: str) -> bool:
+    """Causal self-attention on a TPU under the default schedule, with
+    equal head sizes, a sequence the kernel's blocks divide, and the
+    sequence not sharded.  Everything else keeps the scans below."""
+    _, Sq, _, d = q.shape
+    return (schedule == "dense" and causal and k.shape[1] == Sq
+            and k.shape[-1] == v.shape[-1] == d
+            and splash.block_for(Sq) is not None
+            and all(p is None for p in logical_to_spec(("seq",), (Sq,)))
+            and _platform() == "tpu")
+
+
+def _splash(q, k, v, *, window: int, scale: float):
+    """(B, S, H, d) in and out.  The kernel takes no scale, so q is scaled
+    in float32 and rounded once to its dtype.  Under a mesh each device runs
+    its own batch rows and heads (``jax.shard_map``: GSPMD cannot split the
+    kernel's custom call); heads stay whole when q's and kv's head
+    shardings differ."""
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    run = lambda q, k, v: splash.causal_attention(q, k, v, window=window)
+    if not jax.sharding.get_abstract_mesh().empty:
+        names = ("batch", "heads", None, None)
+        qs, kvs = logical_to_spec(names, q.shape), logical_to_spec(names, k.shape)
+        if tuple(qs)[1:2] != tuple(kvs)[1:2]:
+            names = ("batch", None, None, None)
+            qs = kvs = logical_to_spec(names, q.shape)
+        # check_vma off: the kernel's pallas_call declares no per-axis
+        # variance for its outputs.
+        run = jax.shard_map(run, in_specs=(qs, kvs, kvs), out_specs=qs,
+                            check_vma=False)
+    return run(q, k, v).transpose(0, 2, 1, 3)
 
 
 def _group(q: jax.Array, n_kv: int) -> jax.Array:
@@ -65,6 +117,8 @@ def attention(
     _, Sk, Hkv, _ = k.shape
     dv = v.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    if _kernel_applies(q, k, v, causal, schedule):
+        return _splash(q, k, v, window=window, scale=scale)
     if schedule in ("flash", "flash_triangle"):
         from repro.models.flash import flash
         return flash(q, k, v, causal=causal, chunk_q=chunk_q,

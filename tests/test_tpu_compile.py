@@ -11,17 +11,30 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 
+import re
+import sys
+from collections import Counter
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
+from repro.configs.base import ShapeConfig
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
+from repro.models import ModelOpts, build
+from repro.parallel.plan import ExecutionPlan
+from repro.train.optimizer import OptConfig
+from repro.train.step import compile_train_step
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -34,8 +47,13 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(shape, dtype, sharding):
@@ -77,3 +95,62 @@ def test_ssd_scan_compiles_zamba2_7b(one_chip):
         lambda x, dt, A, b, c: ops.ssd_scan(x, dt, A, b, c,
                                             chunk=cfg.ssm_chunk),
         x, dt, A, bc, bc)
+
+
+def _instructions(text: str) -> str:
+    """The HLO text with one line per instruction: a Pallas kernel's
+    ``kernel_metadata`` attribute holds JSON written over several lines,
+    which puts the call's ``op_name`` on a line of its own."""
+    return re.sub(r"kernel_metadata=\{\n(.*?)\n\}", r"kernel_metadata={\1}",
+                  text, flags=re.S)
+
+
+@pytest.mark.parametrize("plan_kw,window", [
+    ({"gc": True}, 0),
+    ({"dp": 4, "zero_stage": 3, "gc": True}, 256),
+])
+def test_train_step_attention_runs_in_splash_kernels(topo, plan_kw, window):
+    """The train step of a small decoder holds splash attention's kernels
+    under scope ``attn``: forward, its recompute under GC, dQ and dKV.  The
+    dense schedule's scans (``while`` loops) and their stacked score tiles
+    are gone from ``attn``: what ``dynamic-update-slice``s keep that scope
+    write a layer's slot of a stacked attention weight's gradient, fused
+    into its matmul.  The chunks are small, so that the scans would have
+    several q blocks and kv steps."""
+    sys.path.insert(0, str(REPO))
+    try:
+        from perfbench.lib import scopes
+    finally:
+        sys.path.remove(str(REPO))
+
+    cfg = configs.get("starcoder2-3b").with_(
+        n_layers=2, d_model=256, n_heads=2, n_kv_heads=1, head_dim=128,
+        d_ff=512, vocab_size=512, sliding_window=window, attn_chunk_q=128,
+        attn_chunk_k=256)
+    plan = ExecutionPlan(**plan_kw)
+    model = build(cfg, ModelOpts(remat="full", loss_chunk=0))
+    mesh = make_mesh(plan.dp, plan.tp, devices=topo.devices[:plan.n_gpus])
+    specs = model.input_specs(ShapeConfig("train", 512, 4, "train"))
+    lowered, *_ = compile_train_step(model, plan, mesh, OptConfig(), specs)
+    text = _instructions(lowered.compile().as_text())
+    smap = scopes.scope_map(text)
+
+    kernels, stacks, loops = Counter(), set(), 0
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+)", line)
+        if not m or smap.get(m.group(1), (None,))[0] != "attn":
+            continue
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels[m.group(1).rsplit(".", 1)[0], smap[m.group(1)][1]] += 1
+        loops += " while(" in line
+        if "dynamic-update-slice" in m.group(1):
+            stacks.add(re.sub(r"^\w+\[([\d,]*)\].*", r"\1", m.group(2)))
+    assert kernels == {("splash_mha_fwd_residuals", "forward"): 1,
+                       ("splash_mha_fwd_residuals", "recompute"): 1,
+                       ("splash_mha_dq_no_residuals", "backward"): 1,
+                       ("splash_mha_dkv_no_residuals", "backward"): 1}
+    assert loops == 0
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    weights = {",".join(map(str, w.shape))
+               for w in jax.tree.leaves(params["layers"]["attn"])}
+    assert stacks <= weights
