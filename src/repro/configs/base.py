@@ -182,6 +182,8 @@ ARCHS = (
     # Paper-native models used by the Rubick benchmarks (Table 2):
     "gpt2-1.5b",
     "llama2-7b",
+    # One pipeline stage of starcoder2-3b, a chip's share at long context:
+    "starcoder2-3b-stage",
 )
 
 _MODULE_FOR = {a: a.replace("-", "_").replace(".", "_") for a in ARCHS}

@@ -105,18 +105,17 @@ def _instructions(text: str) -> str:
                   text, flags=re.S)
 
 
-@pytest.mark.parametrize("plan_kw,window", [
-    ({"gc": True}, 0),
-    ({"dp": 4, "zero_stage": 3, "gc": True}, 256),
-])
-def test_train_step_attention_runs_in_splash_kernels(topo, plan_kw, window):
-    """The train step of a small decoder holds splash attention's kernels
-    under scope ``attn``: forward, its recompute under GC, dQ and dKV.  The
-    dense schedule's scans (``while`` loops) and their stacked score tiles
-    are gone from ``attn``: what ``dynamic-update-slice``s keep that scope
-    write a layer's slot of a stacked attention weight's gradient, fused
-    into its matmul.  The chunks are small, so that the scans would have
-    several q blocks and kv steps."""
+SPLASH_CALLS = {("splash_mha_fwd_residuals", "forward"): 1,
+                ("splash_mha_fwd_residuals", "recompute"): 1,
+                ("splash_mha_dq_no_residuals", "backward"): 1,
+                ("splash_mha_dkv_no_residuals", "backward"): 1}
+
+
+def _assert_attention_in_splash(topo, plan_kw, window, seq, batch):
+    """Compile a 2-layer decoder's train step at small widths for the
+    described chips, and check what runs under scope ``attn``: splash's four
+    calls, no ``while`` loop, and no ``dynamic-update-slice`` but those of
+    the stacked attention weights' gradients."""
     sys.path.insert(0, str(REPO))
     try:
         from perfbench.lib import scopes
@@ -130,7 +129,7 @@ def test_train_step_attention_runs_in_splash_kernels(topo, plan_kw, window):
     plan = ExecutionPlan(**plan_kw)
     model = build(cfg, ModelOpts(remat="full", loss_chunk=0))
     mesh = make_mesh(plan.dp, plan.tp, devices=topo.devices[:plan.n_gpus])
-    specs = model.input_specs(ShapeConfig("train", 512, 4, "train"))
+    specs = model.input_specs(ShapeConfig("train", seq, batch, "train"))
     lowered, *_ = compile_train_step(model, plan, mesh, OptConfig(), specs)
     text = _instructions(lowered.compile().as_text())
     smap = scopes.scope_map(text)
@@ -145,12 +144,47 @@ def test_train_step_attention_runs_in_splash_kernels(topo, plan_kw, window):
         loops += " while(" in line
         if "dynamic-update-slice" in m.group(1):
             stacks.add(re.sub(r"^\w+\[([\d,]*)\].*", r"\1", m.group(2)))
-    assert kernels == {("splash_mha_fwd_residuals", "forward"): 1,
-                       ("splash_mha_fwd_residuals", "recompute"): 1,
-                       ("splash_mha_dq_no_residuals", "backward"): 1,
-                       ("splash_mha_dkv_no_residuals", "backward"): 1}
+    assert kernels == SPLASH_CALLS
     assert loops == 0
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     weights = {",".join(map(str, w.shape))
                for w in jax.tree.leaves(params["layers"]["attn"])}
     assert stacks <= weights
+
+
+@pytest.mark.parametrize("plan_kw,window", [
+    ({"gc": True}, 0),
+    ({"dp": 4, "zero_stage": 3, "gc": True}, 256),
+])
+def test_train_step_attention_runs_in_splash_kernels(topo, plan_kw, window):
+    """The train step of a small decoder holds splash attention's kernels
+    under scope ``attn``: forward, its recompute under GC, dQ and dKV.  The
+    dense schedule's scans (``while`` loops) and their stacked score tiles
+    are gone from ``attn``: what ``dynamic-update-slice``s keep that scope
+    write a layer's slot of a stacked attention weight's gradient, fused
+    into its matmul.  The chunks are small, so that the scans would have
+    several q blocks and kv steps."""
+    _assert_attention_in_splash(topo, plan_kw, window, 512, 4)
+
+
+def test_banded_train_step_at_8192_runs_in_splash_kernels(topo):
+    """StarCoder2's window (4096) under a sequence twice as long, in blocks
+    of 512, as the one-stage cell runs it: the step holds the same four
+    splash calls under ``attn`` and no scan, and splash's own processing of
+    the band mask keeps 108 of the 256 (q, kv) block pairs (each q block
+    sees its own and at most 8 earlier kv blocks), where the causal mask
+    keeps 136."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask_info as mask_info)
+
+    from repro.kernels import splash
+
+    seq, window = 8192, 4096
+    assert splash.block_for(seq) == 512
+    _assert_attention_in_splash(topo, {"gc": True}, window, seq, 1)
+
+    def kept(window):
+        info, _ = mask_info.process_mask(splash._mask(2, seq, window),
+                                         (512, 512), shrink_grid=False)
+        return int((info.block_mask[0] != 0).sum())
+    assert (kept(window), kept(0)) == (108, 136)
