@@ -26,7 +26,13 @@ from repro.parallel.plan import ExecutionPlan
 from repro.parallel.plan_table import PlanColumns
 
 C_ACT = 34.0          # bytes/token/hidden/layer without GC (bf16 copies)
-C_ACT_GC = 2.0        # checkpointed boundaries
+# Checkpointed boundaries.  Under-counts the runtime's GC on the TPU kernel
+# path, which also keeps splash attention's q, k, v, output and log-sum-exp
+# (``models.transformer._maybe_remat``): on the compiler's peaks for a
+# described v5e, about 9.9 B/token/hidden/layer more for gpt2-1.5b (MHA, head
+# size 64) and 3.1-4.4 more for starcoder2-3b (GQA).  Do not trust the fit
+# check near the device's limit until GC is re-priced.
+C_ACT_GC = 2.0
 FRAMEWORK_OVERHEAD = 4e9
 
 # checkpoint-restore cost model (failure & elasticity engine): a restart

@@ -19,6 +19,12 @@ This wrapper builds the kernel from the shapes it is given:
 The kernel takes no softmax scale: the caller scales q.  Its products take
 q and k in their dtype with float32 accumulation; its forward's PV product
 takes float32 probabilities and v, its backward bf16 ones.
+
+Its backward needs q, k, v, the output and the log-sum-exp.  The kernel
+names its output and log-sum-exp ``RESIDUALS`` (``checkpoint_name``), and
+callers name q, k and v the same, so that a ``jax.checkpoint`` policy that
+saves ``RESIDUALS`` keeps the forward kernel and whatever made its operands
+out of the recompute.
 """
 
 from __future__ import annotations
@@ -29,6 +35,7 @@ import jax
 from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
 BLOCKS = (512, 256, 128)
+RESIDUALS = "splash_residuals"
 
 
 def block_for(seq: int) -> int | None:
@@ -59,5 +66,6 @@ def causal_attention(q, k, v, *, window: int = 0, interpret: bool = False):
     # computed on the host once per mask and block (splash caches them).
     kernel = sa.make_splash_mha_single_device(_mask(heads, seq, window),
                                               block_sizes=blocks,
+                                              residual_checkpoint_name=RESIDUALS,
                                               interpret=interpret)
     return jax.vmap(kernel)(q, k, v)

@@ -28,6 +28,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax._src import mesh as mesh_lib
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.kernels import splash
 from repro.parallel.axes import logical_to_spec
@@ -56,6 +57,16 @@ def _kernel_applies(q, k, v, causal: bool, schedule: str) -> bool:
             and _platform() == "tpu")
 
 
+def _kernel_operand(x):
+    """(B, S, H, d) -> the kernel's (B, H, S, d).  Named ``splash.RESIDUALS``
+    for GC to save as (B, S, H·d) before the transpose, which the recompute
+    then redoes: that layout carries no lane padding when d is 64 (gpt2),
+    and holds the same bytes as the kernel's when 128 divides d."""
+    B, S, H, d = x.shape
+    x = checkpoint_name(x.reshape(B, S, H * d), splash.RESIDUALS)
+    return x.reshape(B, S, H, d).transpose(0, 2, 1, 3)
+
+
 def _splash(q, k, v, *, window: int, scale: float):
     """(B, S, H, d) in and out.  The kernel takes no scale, so q is scaled
     in float32 and rounded once to its dtype.  Under a mesh each device runs
@@ -63,7 +74,7 @@ def _splash(q, k, v, *, window: int, scale: float):
     kernel's custom call); heads stay whole when q's and kv's head
     shardings differ."""
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    q, k, v = map(_kernel_operand, (q, k, v))
     run = lambda q, k, v: splash.causal_attention(q, k, v, window=window)
     if not jax.sharding.get_abstract_mesh().empty:
         names = ("batch", "heads", None, None)

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import splash
 from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import nn
@@ -46,8 +47,14 @@ EMBED, LAYERS, NORM, ATTN, MLP, MOE, LOSS, OPTIMIZER = SCOPES
 
 
 def _maybe_remat(fn, opts: ModelOpts):
+    """GC (``remat="full"``) recomputes the layer from its input, except the
+    splash kernel's residuals (``kernels.splash.RESIDUALS``: q, k, v, its
+    output and log-sum-exp), which it saves: the backward then runs no
+    forward kernel and no q/k/v projection again.  Off the kernel path
+    nothing carries that name, and nothing but the layer input is saved."""
     if opts.remat == "full":
-        return jax.checkpoint(fn)
+        return jax.checkpoint(fn, policy=jax.checkpoint_policies
+                              .save_only_these_names(splash.RESIDUALS))
     if opts.remat == "dots":
         return jax.checkpoint(
             fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)

@@ -105,17 +105,21 @@ def _instructions(text: str) -> str:
                   text, flags=re.S)
 
 
+# GC saves the kernel's residuals (``models.transformer._maybe_remat``), so
+# its recompute holds no splash call: with or without GC, the step runs one
+# forward, one dQ and one dKV call under ``attn``.
 SPLASH_CALLS = {("splash_mha_fwd_residuals", "forward"): 1,
-                ("splash_mha_fwd_residuals", "recompute"): 1,
                 ("splash_mha_dq_no_residuals", "backward"): 1,
                 ("splash_mha_dkv_no_residuals", "backward"): 1}
 
 
 def _assert_attention_in_splash(topo, plan_kw, window, seq, batch):
     """Compile a 2-layer decoder's train step at small widths for the
-    described chips, and check what runs under scope ``attn``: splash's four
-    calls, no ``while`` loop, and no ``dynamic-update-slice`` but those of
-    the stacked attention weights' gradients."""
+    described chips, with full remat where the plan takes GC, and check
+    what runs under scope ``attn``: splash's three calls, no ``while``
+    loop, and no ``dynamic-update-slice`` whose outputs are not all stacks
+    of the attention weights' gradients or of the kernel residuals that GC
+    saves."""
     sys.path.insert(0, str(REPO))
     try:
         from perfbench.lib import scopes
@@ -127,7 +131,8 @@ def _assert_attention_in_splash(topo, plan_kw, window, seq, batch):
         d_ff=512, vocab_size=512, sliding_window=window, attn_chunk_q=128,
         attn_chunk_k=256)
     plan = ExecutionPlan(**plan_kw)
-    model = build(cfg, ModelOpts(remat="full", loss_chunk=0))
+    model = build(cfg, ModelOpts(remat="full" if plan.gc else "none",
+                                 loss_chunk=0))
     mesh = make_mesh(plan.dp, plan.tp, devices=topo.devices[:plan.n_gpus])
     specs = model.input_specs(ShapeConfig("train", seq, batch, "train"))
     lowered, *_ = compile_train_step(model, plan, mesh, OptConfig(), specs)
@@ -136,40 +141,52 @@ def _assert_attention_in_splash(topo, plan_kw, window, seq, batch):
 
     kernels, stacks, loops = Counter(), set(), 0
     for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+)", line)
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) [\w\-]+\(", line)
         if not m or smap.get(m.group(1), (None,))[0] != "attn":
             continue
         if 'custom_call_target="tpu_custom_call"' in line:
             kernels[m.group(1).rsplit(".", 1)[0], smap[m.group(1)][1]] += 1
         loops += " while(" in line
         if "dynamic-update-slice" in m.group(1):
-            stacks.add(re.sub(r"^\w+\[([\d,]*)\].*", r"\1", m.group(2)))
+            stacks.update(re.findall(r"\w+\[([\d,]*)\]", m.group(2)))
     assert kernels == SPLASH_CALLS
     assert loops == 0
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     weights = {",".join(map(str, w.shape))
                for w in jax.tree.leaves(params["layers"]["attn"])}
-    assert stacks <= weights
+    # The kernel's residuals, B a chip's rows: q, k, v as GC names them,
+    # (B, S, H·d), and as the kernel takes them, (B, H, S, d), which a step
+    # without remat saves; the output (B, H, S, d) and the log-sum-exp
+    # (B, H, S); stacked over the L layers.  A fusion that writes a layer's
+    # slot may also hand on the layer's own value.
+    L, B, d = cfg.n_layers, batch // plan.dp, cfg.resolved_head_dim
+    layer = {f"{B},{seq},{h * d}" for h in (cfg.n_heads, cfg.n_kv_heads)}
+    layer |= {f"{B},{h},{seq},{d}" for h in (cfg.n_heads, cfg.n_kv_heads)}
+    layer.add(f"{B},{cfg.n_heads},{seq}")
+    saved = layer | {f"{L},{s}" for s in layer}
+    assert stacks <= weights | saved
 
 
 @pytest.mark.parametrize("plan_kw,window", [
     ({"gc": True}, 0),
     ({"dp": 4, "zero_stage": 3, "gc": True}, 256),
+    ({}, 0),
 ])
 def test_train_step_attention_runs_in_splash_kernels(topo, plan_kw, window):
     """The train step of a small decoder holds splash attention's kernels
-    under scope ``attn``: forward, its recompute under GC, dQ and dKV.  The
-    dense schedule's scans (``while`` loops) and their stacked score tiles
-    are gone from ``attn``: what ``dynamic-update-slice``s keep that scope
-    write a layer's slot of a stacked attention weight's gradient, fused
-    into its matmul.  The chunks are small, so that the scans would have
-    several q blocks and kv steps."""
+    under scope ``attn``: forward, dQ and dKV, and under GC no recompute of
+    the forward, also where the kernel runs inside ``shard_map`` on four
+    chips.  The dense schedule's scans (``while`` loops) and their stacked
+    score tiles are gone from ``attn``: what ``dynamic-update-slice``s keep
+    that scope write a layer's slot of a stacked attention weight's
+    gradient, fused into its matmul.  The chunks are small, so that the
+    scans would have several q blocks and kv steps."""
     _assert_attention_in_splash(topo, plan_kw, window, 512, 4)
 
 
 def test_banded_train_step_at_8192_runs_in_splash_kernels(topo):
     """StarCoder2's window (4096) under a sequence twice as long, in blocks
-    of 512, as the one-stage cell runs it: the step holds the same four
+    of 512, as the one-stage cell runs it: the step holds the same three
     splash calls under ``attn`` and no scan, and splash's own processing of
     the band mask keeps 108 of the 256 (q, kv) block pairs (each q block
     sees its own and at most 8 earlier kv blocks), where the causal mask
