@@ -8,7 +8,6 @@ importing this module changes nothing.
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
-    PYTHONPATH=src python -m repro.launch.dryrun --all --schedule triangle --tag opt
 
 Outputs one JSON row per cell under benchmarks/results/.
 """
@@ -88,7 +87,7 @@ def default_plan(cfg: ModelConfig, shape: ShapeConfig, mesh,
     return plan, opt
 
 
-def run_cell(arch: str, shape_name: str, mesh, *, schedule: str = "dense",
+def run_cell(arch: str, shape_name: str, mesh, *,
              plan_overrides: dict | None = None, verbose: bool = True):
     """Lower + compile one cell.  Returns a result-row dict."""
     cfg = configs.get(arch)
@@ -102,7 +101,6 @@ def run_cell(arch: str, shape_name: str, mesh, *, schedule: str = "dense",
     plan, optcfg = default_plan(cfg, shape, mesh, plan_overrides)
     opts = ModelOpts(
         remat="full" if plan.gc else "none",
-        attn_schedule=schedule,
         loss_chunk=min(2048, shape.seq_len),
     )
     model = build(cfg, opts)
@@ -132,7 +130,6 @@ def run_cell(arch: str, shape_name: str, mesh, *, schedule: str = "dense",
         "plan_tuple": {"dp": plan.dp, "tp": plan.tp, "ga": plan.ga_steps,
                        "zero": plan.zero_stage, "gc": plan.gc,
                        "offload": plan.offload, "sp": plan.sp},
-        "schedule": schedule,
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
         "temp_bytes": getattr(ma, "temp_size_in_bytes", 0),
         "arg_bytes": getattr(ma, "argument_size_in_bytes", 0),
@@ -159,8 +156,6 @@ def main() -> None:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
-    ap.add_argument("--schedule", default="dense",
-                    choices=["dense", "triangle", "flash", "flash_triangle"])
     ap.add_argument("--tag", default="baseline")
     ap.add_argument("--plan-override", default=None,
                     help='JSON, e.g. {"sp": true, "ga_steps": 4}')
@@ -187,7 +182,6 @@ def main() -> None:
             for shape_name in shape_list:
                 try:
                     row = run_cell(arch, shape_name, mesh,
-                                   schedule=args.schedule,
                                    plan_overrides=overrides)
                 except Exception as e:  # a cell failure is a bug — surface it
                     traceback.print_exc()

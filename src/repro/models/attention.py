@@ -1,24 +1,16 @@
-"""Memory-efficient attention in pure JAX (HLO-level flash attention).
+"""Training and prefill attention, by one of three routes that the step
+itself decides; no option chooses among them.
 
-Never materializes the full (Sq, Sk) score matrix: computes online-softmax
-over (chunk_q × chunk_k) tiles via ``lax.scan``, exactly the tiling the
-Pallas kernel (repro.kernels.flash_attention) performs in VMEM on TPU.  On
-CPU dry-runs this keeps per-device activation memory bounded at 32k+ context.
-
-Two schedules:
-  * ``dense``    — scan over all (qi, kj) tiles, masked.  Simple, compact
-                   HLO, but computes ~2× wasted FLOPs for causal masks.
-  * ``triangle`` — unrolled loop over q tiles, each attending only to its
-                   k-prefix (and to its window for sliding-window models).
-                   This is the beyond-paper §Perf optimization: it removes
-                   the masked-out tiles from the compiled FLOPs entirely.
+  * **Splash kernels** (``repro.kernels.splash``: forward, dQ and dKV in
+    VMEM, masked-out blocks skipped) for causal self-attention on a TPU;
+    ``_kernel_applies`` gives the rule.
+  * **Tile scans** everywhere else: online softmax over (chunk_q × chunk_k)
+    tiles through two ``lax.scan``s, so the full (Sq, Sk) score matrix is
+    never built; masked tiles are computed and masked, not skipped.
+  * **Plain masked attention** for lengths the chunks do not divide (the
+    tiny CPU configurations).
 
 GQA/MQA are expressed by grouping query heads over kv heads.
-
-On a TPU, full-sequence causal self-attention under the default schedule
-runs through the splash attention kernels instead (``repro.kernels.splash``:
-forward, dQ and dKV in VMEM, masked-out blocks skipped); see
-``_kernel_applies`` for when.
 """
 
 from __future__ import annotations
@@ -45,12 +37,12 @@ def _platform() -> str:
     return jax.default_backend() if mesh.empty else mesh.devices.flat[0].platform
 
 
-def _kernel_applies(q, k, v, causal: bool, schedule: str) -> bool:
-    """Causal self-attention on a TPU under the default schedule, with
-    equal head sizes, a sequence the kernel's blocks divide, and the
-    sequence not sharded.  Everything else keeps the scans below."""
+def _kernel_applies(q, k, v, causal: bool) -> bool:
+    """Causal self-attention on a TPU, with equal head sizes, a sequence
+    the kernel's blocks divide, and the sequence not sharded.  Everything
+    else keeps the scans below."""
     _, Sq, _, d = q.shape
-    return (schedule == "dense" and causal and k.shape[1] == Sq
+    return (causal and k.shape[1] == Sq
             and k.shape[-1] == v.shape[-1] == d
             and splash.block_for(Sq) is not None
             and all(p is None for p in logical_to_spec(("seq",), (Sq,)))
@@ -95,6 +87,21 @@ def _group(q: jax.Array, n_kv: int) -> jax.Array:
     return q.reshape(B, S, n_kv, Hq // n_kv, d)
 
 
+def _mask(q0, k0, nq: int, nk: int, causal: bool, window: int):
+    """(1, 1, 1, nq, nk): which of the keys at k0 + [0, nk) the queries at
+    q0 + [0, nq) see; None when every query sees every key."""
+    if not causal and not window:
+        return None
+    qpos = q0 + jnp.arange(nq)
+    kpos = k0 + jnp.arange(nk)
+    m = jnp.ones((nq, nk), bool)
+    if causal:
+        m &= qpos[:, None] >= kpos[None, :]
+    if window:
+        m &= qpos[:, None] - kpos[None, :] < window
+    return m[None, None, None]
+
+
 def _tile_attend(qc, kc, vc, mask, m, l, acc, scale):
     """One (cq × ck) tile of online-softmax.  qc: (B,cq,K,G,d); kc/vc: (B,ck,K,d)."""
     s = jnp.einsum("bqkgd,bskd->bkgqs", qc, kc, preferred_element_type=jnp.float32)
@@ -120,27 +127,22 @@ def attention(
     chunk_q: int = 512,
     chunk_k: int = 1024,
     window: int = 0,
-    schedule: str = "dense",
     scale: float | None = None,
 ) -> jax.Array:
-    """Tiled attention.  q: (B,Sq,Hq,d); k,v: (B,Sk,Hkv,d) -> (B,Sq,Hq,d)."""
+    """q: (B,Sq,Hq,d); k,v: (B,Sk,Hkv,d) -> (B,Sq,Hq,dv), by the route the
+    module docstring describes.  Queries sit at the last Sq of the Sk key
+    positions, so Sq != Sk is allowed."""
     B, Sq, Hq, d = q.shape
     _, Sk, Hkv, _ = k.shape
     dv = v.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    if _kernel_applies(q, k, v, causal, schedule):
+    if _kernel_applies(q, k, v, causal):
         return _splash(q, k, v, window=window, scale=scale)
-    if schedule in ("flash", "flash_triangle"):
-        from repro.models.flash import flash
-        return flash(q, k, v, causal=causal, chunk_q=chunk_q,
-                     chunk_k=chunk_k, window=window, scale=scale,
-                     triangle=(schedule == "flash_triangle"))
     qg = _group(q, Hkv)
 
     cq = min(chunk_q, Sq)
     ck = min(chunk_k, Sk)
     if Sq % cq or Sk % ck:
-        # Irregular lengths (tiny smoke configs): plain masked attention.
         return _plain_attention(qg, k, v, causal=causal, window=window, scale=scale)
 
     nq, nk = Sq // cq, Sk // ck
@@ -149,76 +151,25 @@ def attention(
     q_tiles = qg.reshape(B, nq, cq, Hkv, G, d).transpose(1, 0, 2, 3, 4, 5)
     k_tiles = k.reshape(B, nk, ck, Hkv, d).transpose(1, 0, 2, 3, 4)
     v_tiles = v.reshape(B, nk, ck, Hkv, dv).transpose(1, 0, 2, 3, 4)
-    # offset between q and k absolute positions (q block i covers
-    # [off + i*cq, off + (i+1)*cq) in k coordinates) — supports Sq != Sk.
+    # q block i covers [off + i*cq, off + (i+1)*cq) in k coordinates.
     off = Sk - Sq
 
-    def mask_for(qi, kj):
-        if not causal and not window:
-            return None
-        qpos = off + qi * cq + jnp.arange(cq)
-        kpos = kj * ck + jnp.arange(ck)
-        m = jnp.ones((cq, ck), bool)
-        if causal:
-            m &= qpos[:, None] >= kpos[None, :]
-        if window:
-            m &= qpos[:, None] - kpos[None, :] < window
-        return m[None, None, None]                          # (1,1,1,cq,ck)
+    def scan_q(_, qx):
+        qc, qi = qx
 
-    def q_block(qc, qi):
+        def kv_step(carry, kv):
+            kc, vc, kj = kv
+            mask = _mask(off + qi * cq, kj * ck, cq, ck, causal, window)
+            return _tile_attend(qc, kc, vc, mask, *carry, scale), None
+
         m0 = jnp.full((B, Hkv, G, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, cq), jnp.float32)
         a0 = jnp.zeros((B, Hkv, G, cq, dv), jnp.float32)
-
-        if schedule == "triangle":
-            # Only tiles that intersect the causal/window band.
-            kj_hi = nk if not causal else min(nk, (off + (qi + 1) * cq + ck - 1) // ck)
-            kj_lo = 0 if not window else max(0, (off + qi * cq - window + 1) // ck)
-            m, l, acc = m0, l0, a0
-            for kj in range(kj_lo, kj_hi):
-                full_below = causal and (kj + 1) * ck <= off + qi * cq + 1
-                full_inside = (not window) or (qi * cq + off - (kj * ck) < window - ck)
-                mask = None if (full_below and full_inside and causal) else mask_for(qi, kj)
-                if not causal and not window:
-                    mask = None
-                m, l, acc = _tile_attend(qc, k_tiles[kj], v_tiles[kj], mask, m, l, acc, scale)
-            return m, l, acc
-
-        def kv_step(carry, kv):
-            m, l, acc = carry
-            kc, vc, kj = kv
-            mask = mask_for_dyn(qi, kj)
-            m, l, acc = _tile_attend(qc, kc, vc, mask, m, l, acc, scale)
-            return (m, l, acc), None
-
-        def mask_for_dyn(qi_, kj_):
-            if not causal and not window:
-                return None
-            qpos = off + qi_ * cq + jnp.arange(cq)
-            kpos = kj_ * ck + jnp.arange(ck)
-            m = jnp.ones((cq, ck), bool)
-            if causal:
-                m &= qpos[:, None] >= kpos[None, :]
-            if window:
-                m &= qpos[:, None] - kpos[None, :] < window
-            return m[None, None, None]
-
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0), (k_tiles, v_tiles, jnp.arange(nk)))
-        return m, l, acc
+        return None, acc / jnp.maximum(l, 1e-30)[..., None]
 
-    if schedule == "triangle":
-        outs = []
-        for qi in range(nq):
-            m, l, acc = q_block(q_tiles[qi], qi)
-            outs.append((acc / jnp.maximum(l, 1e-30)[..., None]))
-        o = jnp.stack(outs, axis=0)                        # (nq,B,K,G,cq,d)
-    else:
-        def scan_q(_, qx):
-            qc, qi = qx
-            m, l, acc = q_block(qc, qi)
-            return None, acc / jnp.maximum(l, 1e-30)[..., None]
-        _, o = jax.lax.scan(scan_q, None, (q_tiles, jnp.arange(nq)))
+    _, o = jax.lax.scan(scan_q, None, (q_tiles, jnp.arange(nq)))
 
     # (nq, B, Hkv, G, cq, dv) -> (B, Sq, Hq, dv)
     o = o.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, Hq, dv)
@@ -230,14 +181,9 @@ def _plain_attention(qg, k, v, *, causal, window, scale):
     Sk = k.shape[1]
     dv = v.shape[-1]
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k, preferred_element_type=jnp.float32) * scale
-    qpos = (Sk - Sq) + jnp.arange(Sq)
-    kpos = jnp.arange(Sk)
-    m = jnp.ones((Sq, Sk), bool)
-    if causal:
-        m &= qpos[:, None] >= kpos[None, :]
-    if window:
-        m &= qpos[:, None] - kpos[None, :] < window
-    s = jnp.where(m[None, None, None], s, NEG_INF)
+    mask = _mask(Sk - Sq, 0, Sq, Sk, causal, window)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
     return o.reshape(B, Sq, Hkv * G, dv)

@@ -57,7 +57,7 @@ def encode(params, frames, cfg: ModelConfig, opts: ModelOpts):
 
     def body(x, lp):
         h = nn.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        x = x + attn_apply(lp["attn"], h, cfg, positions, opts, causal=False)
+        x = x + attn_apply(lp["attn"], h, cfg, positions, causal=False)
         h = nn.rmsnorm(x, lp["ln2"], cfg.norm_eps)
         return x + nn.ffn_apply(lp["mlp"], h, cfg.act), None
 
@@ -75,16 +75,16 @@ def _cross_kv(lp_x, enc_out, cfg):
     return k, v
 
 
-def _decoder_block(lp, x, enc_out, cfg, positions, opts):
+def _decoder_block(lp, x, enc_out, cfg, positions):
     h = nn.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-    x = x + attn_apply(lp["attn"], h, cfg, positions, opts, causal=True)
+    x = x + attn_apply(lp["attn"], h, cfg, positions, causal=True)
     h = nn.rmsnorm(x, lp["lnx"], cfg.norm_eps)
     k, v = _cross_kv(lp["xattn"], enc_out, cfg)
     B, S, _ = h.shape
     hd = cfg.resolved_head_dim
     q = (h @ lp["xattn"]["wq"]).reshape(B, S, cfg.n_heads, hd)
     o = attention(q, k, v, causal=False, chunk_q=cfg.attn_chunk_q,
-                  chunk_k=cfg.attn_chunk_k, schedule=opts.attn_schedule)
+                  chunk_k=cfg.attn_chunk_k)
     x = x + o.reshape(B, S, -1) @ lp["xattn"]["wo"]
     h = nn.rmsnorm(x, lp["ln2"], cfg.norm_eps)
     return x + nn.ffn_apply(lp["mlp"], h, cfg.act)
@@ -98,7 +98,7 @@ def encdec_forward(params, batch, cfg: ModelConfig, opts: ModelOpts):
     positions = jnp.arange(x.shape[1])[None, :]
 
     def body(x, lp):
-        return _decoder_block(lp, x, enc_out, cfg, positions, opts), None
+        return _decoder_block(lp, x, enc_out, cfg, positions), None
 
     body = jax.checkpoint(body) if opts.remat == "full" else body
     x, _ = jax.lax.scan(body, x, params["dec_layers"])
@@ -148,7 +148,7 @@ def encdec_prefill(params, cache, batch, cfg: ModelConfig, opts: ModelOpts):
         q, k, v = _qkv(lp["attn"], h, cfg, positions)
         B = x.shape[0]
         o = attention(q, k, v, causal=True, chunk_q=cfg.attn_chunk_q,
-                      chunk_k=cfg.attn_chunk_k, schedule=opts.attn_schedule)
+                      chunk_k=cfg.attn_chunk_k)
         x = x + o.reshape(B, S, -1) @ lp["attn"]["wo"]
         sk_l = jax.lax.dynamic_index_in_dim(sk, i, 0, keepdims=False)
         sv_l = jax.lax.dynamic_index_in_dim(sv, i, 0, keepdims=False)
@@ -160,7 +160,7 @@ def encdec_prefill(params, cache, batch, cfg: ModelConfig, opts: ModelOpts):
         hd = cfg.resolved_head_dim
         qx = (h @ lp["xattn"]["wq"]).reshape(B, S, cfg.n_heads, hd)
         o = attention(qx, kx, vx, causal=False, chunk_q=cfg.attn_chunk_q,
-                      chunk_k=cfg.attn_chunk_k, schedule=opts.attn_schedule)
+                      chunk_k=cfg.attn_chunk_k)
         x = x + o.reshape(B, S, -1) @ lp["xattn"]["wo"]
         ck = jax.lax.dynamic_update_index_in_dim(ck, kx.astype(ck.dtype), i, 0)
         cv = jax.lax.dynamic_update_index_in_dim(cv, vx.astype(cv.dtype), i, 0)

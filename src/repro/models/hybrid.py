@@ -46,10 +46,10 @@ def hybrid_init(key, cfg: ModelConfig, dtype=None) -> dict:
     return p
 
 
-def _shared_block(shared, x, cfg, positions, opts):
+def _shared_block(shared, x, cfg, positions):
     sp = jax.tree.map(lambda a: a[0], shared)
     h = nn.rmsnorm(x, sp["ln1"], cfg.norm_eps)
-    x = x + attn_apply(sp["attn"], h, cfg, positions, opts)
+    x = x + attn_apply(sp["attn"], h, cfg, positions)
     h = nn.rmsnorm(x, sp["ln2"], cfg.norm_eps)
     return x + nn.ffn_apply(sp["mlp"], h, cfg.act)
 
@@ -67,7 +67,7 @@ def hybrid_forward(params, batch, cfg: ModelConfig, opts: ModelOpts):
         x = x + mamba2.mamba2_apply(lp["mixer"], h, cfg)
         x = jax.lax.cond(
             (i % cfg.attn_every) == cfg.attn_every - 1,
-            lambda x: _shared_block(params["shared"], x, cfg, positions, opts),
+            lambda x: _shared_block(params["shared"], x, cfg, positions),
             lambda x: x,
             x)
         return x, None
@@ -186,7 +186,7 @@ def hybrid_prefill(params, cache, batch, cfg: ModelConfig, opts: ModelOpts):
             from repro.models.attention import attention
             q, k, v = _qkv(sp["attn"], h, cfg, positions)
             o = attention(q, k, v, causal=True, chunk_q=cfg.attn_chunk_q,
-                          chunk_k=cfg.attn_chunk_k, schedule=opts.attn_schedule)
+                          chunk_k=cfg.attn_chunk_k)
             B = x.shape[0]
             x = x + o.reshape(B, S, -1) @ sp["attn"]["wo"]
             app_i = i // cfg.attn_every

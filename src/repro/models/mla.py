@@ -2,10 +2,10 @@
 
 Queries through a rank-``q_lora_rank`` bottleneck; keys/values through a
 rank-``kv_lora_rank`` compressed latent ``c_kv`` plus a shared rope key.
-Training/prefill decompresses to per-head K/V and calls the tiled flash
-attention.  Decode caches ONLY (c_kv, k_pe) — the MLA memory win — and uses
-the absorbed-weight formulation so scores are computed directly in latent
-space.
+Training/prefill decompresses to per-head K/V and calls
+``attention.attention``.  Decode caches ONLY (c_kv, k_pe) — the MLA memory
+win — and uses the absorbed-weight formulation so scores are computed
+directly in latent space.
 """
 
 from __future__ import annotations
@@ -56,7 +56,7 @@ def _compress_kv(p, x, cfg: ModelConfig, positions):
     return c_kv, k_pe
 
 
-def mla_attention(p, x, cfg: ModelConfig, positions, *, schedule="dense"):
+def mla_attention(p, x, cfg: ModelConfig, positions):
     """Full (train/prefill) MLA.  x: (B,S,D) -> (B,S,D)."""
     B, S, _ = x.shape
     H, dn, dr, dvh = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -68,8 +68,7 @@ def mla_attention(p, x, cfg: ModelConfig, positions, *, schedule="dense"):
         [k_nope, jnp.broadcast_to(k_pe[:, :, None, :], (B, S, H, dr))], axis=-1)
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     o = attention(q, k, v, causal=True, chunk_q=cfg.attn_chunk_q,
-                  chunk_k=cfg.attn_chunk_k, schedule=schedule,
-                  scale=1.0 / math.sqrt(dn + dr))
+                  chunk_k=cfg.attn_chunk_k, scale=1.0 / math.sqrt(dn + dr))
     return o.reshape(B, S, H * dvh) @ p["wo"]
 
 

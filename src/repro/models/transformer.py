@@ -26,7 +26,6 @@ from repro.parallel.axes import shard
 class ModelOpts:
     """Runtime/compilation knobs (NOT architecture — see ModelConfig)."""
     remat: str = "none"              # none | full | dots
-    attn_schedule: str = "dense"     # dense | triangle
     loss_chunk: int = 2048
     moe_token_chunk: int = 65536
     mtp: bool = True
@@ -100,16 +99,15 @@ def _qkv(p, x, cfg: ModelConfig, positions):
     return q, k, v
 
 
-def attn_apply(p, x, cfg: ModelConfig, positions, opts: ModelOpts,
-               causal: bool = True, kv_override=None):
+def attn_apply(p, x, cfg: ModelConfig, positions, causal: bool = True,
+               kv_override=None):
     """Full-sequence attention.  kv_override: (k, v) for cross-attention."""
     B, S, _ = x.shape
     q, k, v = _qkv(p, x, cfg, positions)
     if kv_override is not None:
         k, v = kv_override
     o = attention(q, k, v, causal=causal, chunk_q=cfg.attn_chunk_q,
-                  chunk_k=cfg.attn_chunk_k, window=cfg.sliding_window,
-                  schedule=opts.attn_schedule)
+                  chunk_k=cfg.attn_chunk_k, window=cfg.sliding_window)
     o = shard(o, "batch", "seq", "heads", None)
     return o.reshape(B, S, -1) @ p["wo"]
 
@@ -158,10 +156,9 @@ def block_apply(lp, x, cfg: ModelConfig, positions, opts: ModelOpts):
         h = nn.rmsnorm(x, lp["ln1"], cfg.norm_eps)
     with jax.named_scope(ATTN):
         if cfg.mla:
-            a = mla_mod.mla_attention(lp["attn"], h, cfg, positions,
-                                      schedule=opts.attn_schedule)
+            a = mla_mod.mla_attention(lp["attn"], h, cfg, positions)
         else:
-            a = attn_apply(lp["attn"], h, cfg, positions, opts)
+            a = attn_apply(lp["attn"], h, cfg, positions)
     x = shard(x + a, "batch", "seq", "embed")
     with jax.named_scope(NORM):
         h = nn.rmsnorm(x, lp["ln2"], cfg.norm_eps)
@@ -383,8 +380,7 @@ def _prefill_stack(stack_params, stack_cache, x, cfg, opts, positions):
             a, i, 0, keepdims=False), stack_params)
         h = nn.rmsnorm(x, lp["ln1"], cfg.norm_eps)
         if cfg.mla:
-            a = mla_mod.mla_attention(lp["attn"], h, cfg, positions,
-                                      schedule=opts.attn_schedule)
+            a = mla_mod.mla_attention(lp["attn"], h, cfg, positions)
             c_kv, k_pe = mla_mod._compress_kv(lp["attn"], h, cfg, positions)
             c_l = jax.lax.dynamic_index_in_dim(cache["c"], i, 0, keepdims=False)
             pe_l = jax.lax.dynamic_index_in_dim(cache["pe"], i, 0, keepdims=False)
@@ -398,8 +394,7 @@ def _prefill_stack(stack_params, stack_cache, x, cfg, opts, positions):
             B, S, _ = h.shape
             q, k, v = _qkv(lp["attn"], h, cfg, positions)
             o = attention(q, k, v, causal=True, chunk_q=cfg.attn_chunk_q,
-                          chunk_k=cfg.attn_chunk_k, window=cfg.sliding_window,
-                          schedule=opts.attn_schedule)
+                          chunk_k=cfg.attn_chunk_k, window=cfg.sliding_window)
             a = o.reshape(B, S, -1) @ lp["attn"]["wo"]
             k_l = jax.lax.dynamic_index_in_dim(cache["k"], i, 0, keepdims=False)
             v_l = jax.lax.dynamic_index_in_dim(cache["v"], i, 0, keepdims=False)

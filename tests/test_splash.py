@@ -3,8 +3,8 @@
 ``kernels/splash.py`` in interpret mode against ``kernels/ref.attention_ref``
 (forward, and the gradients of q, k and v through the kernel's own
 backward), and the dispatch in ``models/attention.attention``: the kernel
-only for causal self-attention on a TPU under the default schedule, the
-scans, unchanged, for everything else.  A test that needs the TPU branch
+only for causal self-attention on a TPU, the scans, unchanged, for
+everything else.  A test that needs the TPU branch
 says so by replacing ``attention._platform``.
 """
 
@@ -43,6 +43,7 @@ def _bhsd(x):
     (4, 2, 128, 512, 0, False),
     (4, 4, 128, 256, 200, False),
     (4, 2, 64, 256, 0, True),
+    (8, 1, 256, 256, 0, False),   # gemma-2b: MQA at head size 256
 ])
 def test_kernel_matches_reference(monkeypatch, Hq, Hkv, d, S, window, remat):
     """In 128-blocks (the rule's smallest), so that each case has several
@@ -75,7 +76,7 @@ def test_block_rule():
 
 @pytest.mark.parametrize("case", [
     "cpu", "seq_not_128", "cross", "not_causal", "unequal_heads",
-    "schedule", "seq_sharded",
+    "seq_sharded",
 ])
 def test_dispatch_keeps_the_scans(monkeypatch, case):
     """Outside its rule the dispatch leaves attention() bit for bit as the
@@ -83,15 +84,13 @@ def test_dispatch_keeps_the_scans(monkeypatch, case):
     q, k, v = _qkv(200 if case == "seq_not_128" else 256, 4, 2, 32,
                    Sk=384 if case == "cross" else None,
                    dv=16 if case == "unequal_heads" else None)
-    kw = dict(causal=case != "not_causal", chunk_q=64, chunk_k=128,
-              schedule="triangle" if case == "schedule" else "dense")
+    kw = dict(causal=case != "not_causal", chunk_q=64, chunk_k=128)
     if case != "cpu":
         monkeypatch.setattr(attn_mod, "_platform", lambda: "tpu")
     rules = (logical_axis_rules({"seq": "model"}, {"model": 1})
              if case == "seq_sharded" else contextlib.nullcontext())
     with rules:
-        assert not attn_mod._kernel_applies(q, k, v, kw["causal"],
-                                            kw["schedule"])
+        assert not attn_mod._kernel_applies(q, k, v, kw["causal"])
         out = jax.jit(partial(attn_mod.attention, **kw))(q, k, v)
         monkeypatch.setattr(attn_mod, "_kernel_applies", lambda *a: False)
         scans = jax.jit(partial(attn_mod.attention, **kw))(q, k, v)

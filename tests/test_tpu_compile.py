@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.configs.base import ShapeConfig
-from repro.kernels import ops
+from repro.kernels import ops, splash
 from repro.launch.mesh import make_mesh
 from repro.models import ModelOpts, build
 from repro.parallel.plan import ExecutionPlan
@@ -65,12 +65,13 @@ def _assert_mosaic(fn, *specs):
     assert "tpu_custom_call" in text
 
 
-def test_flash_attention_compiles_gemma_2b(one_chip):
+def test_splash_compiles_gemma_2b(one_chip):
+    """MQA at head size 256, in the kernel's (B, H, S, d) layout."""
     cfg = configs.get("gemma-2b")
     B, S, d = 1, 2048, cfg.resolved_head_dim
-    q = _spec((B, S, cfg.n_heads, d), jnp.bfloat16, one_chip)
-    kv = _spec((B, S, cfg.n_kv_heads, d), jnp.bfloat16, one_chip)
-    _assert_mosaic(lambda q, k, v: ops.flash_attention(q, k, v), q, kv, kv)
+    q = _spec((B, cfg.n_heads, S, d), jnp.bfloat16, one_chip)
+    kv = _spec((B, cfg.n_kv_heads, S, d), jnp.bfloat16, one_chip)
+    _assert_mosaic(splash.causal_attention, q, kv, kv)
 
 
 def test_wkv6_compiles_rwkv6_1_6b(one_chip):
@@ -176,7 +177,7 @@ def test_train_step_attention_runs_in_splash_kernels(topo, plan_kw, window):
     """The train step of a small decoder holds splash attention's kernels
     under scope ``attn``: forward, dQ and dKV, and under GC no recompute of
     the forward, also where the kernel runs inside ``shard_map`` on four
-    chips.  The dense schedule's scans (``while`` loops) and their stacked
+    chips.  The tile scans (``while`` loops) and their stacked
     score tiles are gone from ``attn``: what ``dynamic-update-slice``s keep
     that scope write a layer's slot of a stacked attention weight's
     gradient, fused into its matmul.  The chunks are small, so that the
@@ -193,8 +194,6 @@ def test_banded_train_step_at_8192_runs_in_splash_kernels(topo):
     keeps 136."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask_info as mask_info)
-
-    from repro.kernels import splash
 
     seq, window = 8192, 4096
     assert splash.block_for(seq) == 512
